@@ -170,6 +170,9 @@ def parse_args(argv: list[str]) -> Args:
 def main() -> None:
     import importlib
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     args = parse_args(sys.argv[1:])
     modules = SMOKE_MODULES if args.smoke and not args.filters else MODULES
     if args.smoke:

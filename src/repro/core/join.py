@@ -99,8 +99,15 @@ def naive_join(col_r: Collection, col_s: Collection | str | None = None,
 
 @jax.jit
 def _overlap_matrix(tokens_r: jnp.ndarray, tokens_s: jnp.ndarray) -> jnp.ndarray:
+    """int32[NR, NS] overlaps of every row pair — the oracle's own merge by
+    ``searchsorted``, independent of the drivers' gathered verification."""
+    def overlap(tok_r, tok_s):
+        idx = jnp.clip(jnp.searchsorted(tok_s, tok_r), 0, tok_s.shape[0] - 1)
+        hit = (tok_s[idx] == tok_r) & (tok_r != PAD_TOKEN)
+        return jnp.sum(hit.astype(jnp.int32))
+
     def row_vs_all(row):
-        return jax.vmap(lambda s: verify._row_overlap(row, s))(tokens_s)
+        return jax.vmap(lambda s: overlap(row, s))(tokens_s)
 
     return jax.vmap(row_vs_all)(tokens_r)
 
@@ -205,7 +212,7 @@ def _resident_block_step(
     n_cand = jnp.sum(cand, dtype=jnp.int32)
     ii, jj = jnp.nonzero(cand, size=cap, fill_value=0)
     slot_ok = jnp.arange(cap) < n_cand
-    o = verify.pairwise_overlap(tokens_r[ii], tokens_s[jj])
+    o = verify.gathered_overlap(tokens_r, ii, tokens_s, jj, slot_ok)
     # Integer-exact acceptance (min_overlap_table): bit-identical to the
     # f64 oracle — f32 thresholds may only ever *prune*, never accept.
     need = bounds.min_overlap_gather(sim, need_tab, lengths_r[ii],
@@ -544,7 +551,6 @@ def _build_ring_sweep_fn(mesh, axes, *, shard_r: int, shard_s: int, cap: int,
                          rs_join: bool):
     """Compile (once per static ring config) the jitted shard_map sweep."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     axis_name = axes if len(axes) > 1 else axes[0]
     n_dev = int(np.prod([mesh.shape[a] for a in axes]))
@@ -568,7 +574,7 @@ def _build_ring_sweep_fn(mesh, axes, *, shard_r: int, shard_s: int, cap: int,
             # Fixed-capacity compaction (Algorithm 8's local candidate list).
             ii, jj = jnp.nonzero(cand, size=cap, fill_value=0)
             slot_valid = jnp.arange(cap) < n_cand
-            ok = verify.pairwise_overlap(tok[ii], s_tok[jj])
+            ok = verify.gathered_overlap(tok, ii, s_tok, jj, slot_valid)
             need = bounds.min_overlap_gather(sim, ntab, length[ii], s_len[jj])
             ok_mask = slot_valid & (ok >= need)
             out_pairs = jnp.stack([ii + my * shard_r,
@@ -589,12 +595,12 @@ def _build_ring_sweep_fn(mesh, axes, *, shard_r: int, shard_s: int, cap: int,
         counters = jnp.stack([cand, ver, ovf])[None]  # (1, 3) per device
         return pairs.reshape(-1, 2), valid.reshape(-1), counters, overflow[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec,) * 6 + (P(),),
         out_specs=(P(axes),) * 4,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -52,6 +52,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import bitmap as bm
 from repro.core import expected, verify
@@ -110,8 +111,6 @@ def _build_sharded_chunk_fn(mesh, axes, *, sim: str, tau: float, cap: int,
     allgather-compact reduce, and stage 3 on each device's slice of the
     globally deduped candidate list.
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
 
     axis_name = axes if len(axes) > 1 else axes[0]
     n_dev = int(np.prod([mesh.shape[a] for a in axes]))
@@ -158,12 +157,12 @@ def _build_sharded_chunk_fn(mesh, axes, *, sim: str, tau: float, cap: int,
         counters = jnp.stack([n_exp, n_slice, n_bm, n_ok])[None]  # (1, 4)
         return pairs, counters
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axes),) * 4 + (P(),) * 13,
         out_specs=(P(axes), P(axes)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -230,7 +229,7 @@ def sharded_indexed_join_prepared(
         words_s = prep_s.bitmap_words(b, chosen, mix=mix)
     lo_np, hi_np, lo_d, hi_d = prep_s.length_window_int(sim, tau)
     ps_d = jnp.asarray(ps_np)
-    slabs = sharded.device_arrays()
+    slabs = sharded.device_arrays(NamedSharding(mesh, P(axes)))
     vocab_d, tid_d = post.device_arrays()[:2]
     scale = post.max_len + 1
     need_tab = verify.min_overlap_table_dev(

@@ -54,8 +54,11 @@ from repro.kernels import ops as kops
 _INT32_MAX = np.int32(np.iinfo(np.int32).max)
 # Auto-sized chunk buffers are capped here; a chunk whose (exact, host
 # int64) expansion count exceeds it escalates to the dense fallback instead
-# of allocating multi-GiB device buffers (or wrapping int32 on device).
-_MAX_AUTO_CAPACITY = 1 << 26
+# of allocating buffers the device cannot hold (or wrapping int32 on
+# device).  The fused chunk step needs about 1 KiB of device memory per
+# slot (v5e compile, 224-token rows: 1.1 GB of temporaries at 2^20 slots),
+# so 2^22 slots keep it near 4 GiB of a 16 GB chip.
+_MAX_AUTO_CAPACITY = 1 << 22
 
 
 def _windowed_ranges(vocab, vocab_tid, post_key, probe_tokens, probe_prefix,
@@ -203,7 +206,8 @@ def verdict_and_verify(
         sim=sim, tau=tau, cutoff=cutoff, impl=impl)
     cand_mask = slot_ok & bm_pass
     n_bitmap = jnp.sum(cand_mask, dtype=jnp.int32)
-    o = verify.pairwise_overlap(tokens_r[safe_r], probe_tokens[safe_s])
+    o = verify.gathered_overlap(tokens_r, safe_r, probe_tokens, safe_s,
+                                cand_mask)
     # Integer-exact acceptance (min_overlap_table) — identical to the
     # f64 oracle; f32 thresholds are prune-only in this driver too.
     need = bounds.min_overlap_gather(
@@ -322,36 +326,60 @@ def chunk_step_spec(
     return args, statics
 
 
+@functools.partial(
+    jax.jit, static_argnames=("sim", "tau", "cutoff", "impl", "self_join"))
+def _fallback_mask(words_r, lengths_r, words_c, lengths_c, lo_c, hi_c, s0,
+                   *, sim, tau, cutoff, impl, self_join):
+    """Grid verdict ∩ length window over R × chunk, on device ->
+    ``(mask bool[NR, C], window cells per R row, R rows with a candidate)``."""
+    cand = kops.candidate_matrix(
+        words_r, words_c, lengths_r, lengths_c, sim=sim, tau=tau,
+        self_join=False, cutoff=cutoff, impl=impl)
+    lr = lengths_r[:, None]
+    win = ((lr >= lo_c[None, :]) & (lr <= hi_c[None, :])
+           & (lr > 0) & (lengths_c[None, :] > 0))
+    if self_join:
+        win &= (jnp.arange(lr.shape[0])[:, None]
+                < (s0 + jnp.arange(lengths_c.shape[0]))[None, :])
+    mask = cand & win
+    return mask, jnp.sum(win, axis=1, dtype=jnp.int32), jnp.any(mask, axis=1)
+
+
 def _dense_chunk_fallback(tokens_r, lengths_r, words_r, tokens_c, lengths_c,
                           words_c, lo_c, hi_c, s0, *, sim, tau, cutoff, impl,
                           self_join):
     """Dense escalation for a probe chunk whose expansion overflowed a
-    forced capacity: grid verdict over R × chunk, host compaction, batched
-    exact verification (the blocked driver's classic route).
+    forced capacity: grid verdict over R × chunk on device, then only the
+    R rows holding a candidate cross to the host for compaction, and
+    batched exact verification (the blocked driver's classic route).
 
     Returns ``(n_window_cells, n_bitmap, verified sorted-space pairs)``.
     """
-    cand = np.asarray(kops.candidate_matrix(
-        words_r, words_c, lengths_r, lengths_c, sim=sim, tau=float(tau),
-        self_join=False, cutoff=int(cutoff), impl=impl))
-    np_lr = np.asarray(lengths_r)
-    np_ls = np.asarray(lengths_c)
-    win = ((np_lr[:, None] >= np.asarray(lo_c)[None, :])
-           & (np_lr[:, None] <= np.asarray(hi_c)[None, :])
-           & (np_lr[:, None] > 0) & (np_ls[None, :] > 0))
-    if self_join:
-        win &= (np.arange(len(np_lr))[:, None]
-                < (s0 + np.arange(len(np_ls)))[None, :])
-    cand = cand & win
-    n_win = int(win.sum())
-    ii, jj = np.nonzero(cand)
-    if len(ii) == 0:
+    mask, win_rows, any_rows = _fallback_mask(
+        words_r, lengths_r, words_c, lengths_c, jnp.asarray(lo_c),
+        jnp.asarray(hi_c), jnp.int32(s0), sim=sim, tau=float(tau),
+        cutoff=int(cutoff), impl=impl, self_join=self_join)
+    n_win = int(np.asarray(win_rows).sum(dtype=np.int64))
+    rows = np.flatnonzero(np.asarray(any_rows))
+    if len(rows) == 0:
         return n_win, 0, np.zeros((0, 2), dtype=np.int64)
+    # Row and pair counts are padded to power-of-two buckets so the gather
+    # and the verification compile once per bucket, not once per count.
+    sub = np.asarray(jnp.take(mask, jnp.asarray(_pad_pow2(rows)), axis=0))
+    ii, jj = np.nonzero(sub[:len(rows)])
+    ii = rows[ii]
     ok = np.asarray(verify.verify_pairs_rs(
         tokens_r, lengths_r, tokens_c, lengths_c,
-        jnp.asarray(ii), jnp.asarray(jj), sim, float(tau)))
+        jnp.asarray(_pad_pow2(ii)), jnp.asarray(_pad_pow2(jj)),
+        sim, float(tau)))[:len(ii)]
     pairs = np.stack([ii[ok], jj[ok] + s0], axis=1).astype(np.int64)
     return n_win, len(ii), pairs
+
+
+def _pad_pow2(a: np.ndarray) -> np.ndarray:
+    """``a`` padded with its first element to a power-of-two length."""
+    return np.pad(a, (0, _bucket_capacity(len(a), floor=1) - len(a)),
+                  mode="edge")
 
 
 def _pad_chunk(a, rows: int, fill):

@@ -214,14 +214,17 @@ class ShardedPostings:
     def slab_width(self) -> int:
         return int(self.post_set.shape[1])
 
-    def device_arrays(self):
-        """(post_set, post_pos, post_len, post_key) stacked per shard as jnp
-        device arrays, cached (the shard_map inputs with the sharded spec)."""
-        if self._device is None:
-            import jax.numpy as jnp
-            self._device = tuple(jnp.asarray(a) for a in (
+    def device_arrays(self, sharding):
+        """(post_set, post_pos, post_len, post_key) stacked per shard as
+        device arrays placed with ``sharding`` — the driver passes the
+        mesh's row sharding, so slab ``k`` lives on device ``k`` — cached
+        per sharding (the shard_map inputs with the sharded spec)."""
+        if self._device is None or self._device[0] != sharding:
+            import jax
+            arrays = tuple(jax.device_put(a, sharding) for a in (
                 self.post_set, self.post_pos, self.post_len, self.post_key))
-        return self._device
+            self._device = (sharding, arrays)
+        return self._device[1]
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return (f"ShardedPostings(n_shards={self.n_shards}, "

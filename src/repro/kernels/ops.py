@@ -8,14 +8,19 @@ Handles tile padding, implementation dispatch, and the CPU story:
 * ``impl='ref'`` (the pure-jnp oracle) is the default *performance* path on
   CPU so that benchmarks and the data pipeline stay fast in this container.
 
-``impl='auto'`` resolves to: Pallas-SWAR for b < 512, Pallas-MXU-bitplane for
-b >= 512 on TPU; ref on CPU.  The pairwise (1-D candidate stream) ops have
-their own resolution (:func:`_resolve_pairwise_impl`): ``auto`` picks the
-candidate-major tiled SWAR kernel for b < 512 and the batched bit-plane MXU
-kernel for b >= 512 on TPU, so large-b candidate verdicts run on the
-systolic array just like the dense grid path; ``entry_filter`` (pure
-integer filtering, no bitmap words) maps the mxu impls to their elementwise
-equivalents.
+``impl='auto'`` resolves to ``ref`` on CPU.  On TPU it resolves only to
+kernels that compile for the chip at every bitmap width (b = 64..1024 is
+pinned by ``tests/test_chip_compile.py``):
+
+* the dense ops (``hamming_matrix``, ``candidate_matrix``,
+  ``count_candidates``) -> ``swar``, the word-unrolled VPU tile kernel;
+* ``pair_verdict`` (1-D candidate stream, :func:`_resolve_pairwise_impl`)
+  -> ``swar_tiled``, the candidate-major streaming kernel;
+* ``entry_filter`` (pure integer filtering, no bitmap words) -> ``swar``.
+
+The other formulations (``mxu`` bit-plane, the word-loop pairwise ``swar``)
+stay reachable by explicit ``impl=`` only; which one wins where is a
+measurement, not a default.
 """
 
 from __future__ import annotations
@@ -52,7 +57,7 @@ def resolve_impl(impl: str, b: int) -> str:
         return impl
     if not _on_tpu():
         return "ref"
-    return "mxu" if b >= 512 else "swar"
+    return "swar"
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "interpret", "tile"))
@@ -214,17 +219,17 @@ def count_candidates(
 def _resolve_pairwise_impl(impl: str, b: int) -> str:
     """Pairwise (1-D candidate stream) dispatch.
 
-    ``auto`` resolves to ref on CPU; on TPU to the candidate-major tiled
-    SWAR kernel for b < 512 and the batched bit-plane MXU kernel for
-    b >= 512 (the 1-D analogue of the dense grid dispatch — the pairwise
-    inner product is a batched ``dot_general``, so large-b verdicts run on
-    the systolic array too).  Explicit impls pass through unchanged.
+    ``auto`` resolves to ref on CPU and, on TPU, to the candidate-major
+    tiled SWAR kernel at every b — the one pairwise formulation that
+    compiles for the chip (the word-loop ``swar`` needs a dynamic lane
+    slice, the bit-plane ``mxu`` a batched ``dot_general``, and Mosaic
+    lowers neither).  Explicit impls pass through unchanged.
     """
     if impl != "auto":
         return impl
     if not _on_tpu():
         return "ref"
-    return "mxu" if b >= 512 else "swar_tiled"
+    return "swar_tiled"
 
 
 def _resolve_entry_impl(impl: str) -> str:

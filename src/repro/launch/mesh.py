@@ -15,11 +15,7 @@ import jax
 
 
 def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the installed JAX has it (added post-0.4)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -176,7 +176,7 @@ class JoinSession:
         # is never one an in-flight batch is still consuming.
         self.transfer = TransferPool(depth=self.pipeline_depth + 1,
                                      device=device)
-        self._cap_hints: Dict[Tuple[int, int, int], int] = {}
+        self._shape_hints: Dict[int, Tuple[int, int, int]] = {}
         self.requests = 0
         self.coalesced_requests = 0
         self.sequential_requests = 0
@@ -318,11 +318,13 @@ class JoinSession:
         encounter — on a CPU backend a single XLA compile is ~1000x a flush,
         so a cold bucket hit mid-traffic stalls every queued request behind
         it.  Serving systems pre-warm instead: given representative
-        ``sample`` requests, this flushes one synthetic group per
-        power-of-two row bucket up to ``max_batch`` — each rung calibrates
-        its own capacity hint and compiles its entrypoint, so every
-        steady-state group lands on an already-traced entrypoint.  Results
-        are discarded; engine/session counters do advance (warmup is real
+        ``sample`` requests, this sets every power-of-two row bucket up to
+        ``max_batch`` to the widest set and longest prefix in the sample,
+        serves the whole sample in ``max_batch`` groups (the full-batch
+        capacity then covers its heaviest group), and flushes one synthetic
+        group per row bucket to calibrate its capacity and compile its
+        entrypoint.  Results are
+        discarded; engine/session counters do advance (warmup is real
         traffic).
 
         Returns the number of entrypoints compiled.  Steady-state traffic
@@ -349,13 +351,26 @@ class JoinSession:
                 i += 1
             self.flush()
 
-        # Calibrate the cap hint on a full batch first, so the ladder below
-        # compiles every row bucket at the final (largest) capacity.
-        flush_rows(mb)
+        # Every rung compiles at the sample's widest set and longest prefix.
+        live = [req for req in sample if req.num_sets]
+        lp = pow2_bucket(max((self._prepass(req)[1] for req in live),
+                             default=1), floor=1)
+        width = max(lp, pow2_bucket(max((req.max_len for req in live),
+                                        default=1), floor=8))
+        rungs = []
         rung = 16  # the dispatch row-bucket floor
         while rung <= pow2_bucket(mb, floor=16):
-            flush_rows(min(rung, mb))
+            hw, hlp, hcap = self._shape_hints.get(rung, (0, 0, 0))
+            self._shape_hints[rung] = (max(hw, width), max(hlp, lp), hcap)
+            rungs.append(rung)
             rung *= 2
+        # The whole sample in max_batch groups calibrates the full-batch
+        # capacity on its heaviest group; then one group per rung.
+        for req in sample:
+            self.submit(req)
+        self.flush()
+        for rung in rungs:
+            flush_rows(min(rung, mb))
         return self.entrypoints.stats()["traces"] - before
 
     def stats_summary(self) -> Dict[str, object]:
@@ -438,17 +453,19 @@ class JoinSession:
         width = max(width, lp)
         n_exp_total = sum(f.n_exp for f in fast)
         cap = pow2_bucket(max(n_exp_total, 1), floor=128)
-        # Monotone capacity hint per shape bucket: group compositions vary
-        # run to run, and letting every n_exp total pick its own pow2 cap
-        # would keep minting fresh entrypoints (and traces) near bucket
-        # boundaries.  Reusing the largest cap seen for this row bucket
-        # keeps one steady-state entrypoint per bucket without oversizing
-        # small groups (cap slots beyond n_generated are padding, but the
-        # dedup sort still pays for them) — ``warm_buckets`` calibrates one
-        # representative cap per rung before traffic.
-        hint_key = (cb, width, lp)
-        cap = max(cap, self._cap_hints.get(hint_key, 0))
-        self._cap_hints[hint_key] = cap
+        # Monotone shape hints per row bucket: group compositions vary run
+        # to run, and letting every group pick its own pow2 width, prefix
+        # width and cap would keep minting fresh entrypoints (and traces)
+        # whenever a group is narrower or lighter than an earlier one.
+        # Reusing the largest (width, lp, cap) seen for this row bucket
+        # keeps one steady-state entrypoint per bucket; the padding is
+        # exact (PAD tokens never match, prefix slots past a row's prefix
+        # are masked, cap slots past n_generated are empty) — the dedup
+        # sort still pays for the slots.  ``warm_buckets`` sets every
+        # rung's hints from a representative sample before traffic.
+        hw, hlp, hcap = self._shape_hints.get(cb, (0, 0, 0))
+        width, lp, cap = max(width, hw), max(lp, hlp), max(cap, hcap)
+        self._shape_hints[cb] = (width, lp, cap)
 
         tokens = np.full((cb, width), PAD_TOKEN, dtype=np.int32)
         lengths = np.zeros((cb,), dtype=np.int32)

@@ -1,0 +1,28 @@
+"""The persistent compile cache has exactly one home per run."""
+
+import jax
+import pytest
+
+from repro import compile_cache
+
+
+@pytest.fixture
+def cache_dir_config():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_env_dir_wins_and_nothing_else_is_set(monkeypatch, cache_dir_config):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_fixed_in_repo_dir_without_env(monkeypatch, cache_dir_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable()
+    assert path == str(compile_cache.REPO_ROOT / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path
+    assert compile_cache.enable() == path  # the same path every call

@@ -179,6 +179,26 @@ def test_warm_buckets_precompiles_ladder(corpus):
     assert sess.entrypoints.stats()["traces"] == warm
 
 
+def test_warm_buckets_covers_narrower_traffic(corpus):
+    """Requests the sample did not include, with shorter sets and prefixes
+    than any sampled group, run on the warm entrypoints — exactly."""
+    by_len = sorted(corpus.as_lists(), key=len)
+    sess = JoinSession(corpus, SIM, TAU, max_batch=16, max_wait=0.0)
+    assert sess.warm_buckets([from_lists([s]) for s in by_len[-16:]]) >= 1
+    warm = sess.entrypoints.stats()["traces"]
+    traffic = [from_lists([s]) for s in by_len[:40]]
+    tickets = [sess.submit(r) for r in traffic]
+    sess.flush()
+    assert sess.entrypoints.stats()["traces"] == warm
+    oracle = JoinEngine(sess.prepared, SIM, TAU, plan=sess.plan)
+    for t, req in zip(tickets, traffic):
+        pairs, stats = t.result()
+        want_pairs, want_stats = oracle.probe(req, return_stats=True)
+        assert t.route == "coalesced"
+        np.testing.assert_array_equal(pairs, want_pairs)
+        assert stats == want_stats
+
+
 def test_session_probe_matches_engine_semantics(session, oracle, corpus):
     req = from_lists(corpus.as_lists()[:3], pad_to=_PAD)
     pairs, stats = session.probe(req)
