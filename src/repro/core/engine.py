@@ -37,6 +37,7 @@ from typing import Deque, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core import bitmap as bm
 from repro.core import bounds
 from repro.core.collection import Collection
@@ -57,14 +58,15 @@ class PreparedCollection:
     """
 
     def __init__(self, source: Collection):
-        order = np.argsort(source.lengths, kind="stable")
-        inverse = np.empty_like(order)
-        inverse[order] = np.arange(len(order))
+        with tracing.span("prepare.sort"):
+            order = np.argsort(source.lengths, kind="stable")
+            inverse = np.empty_like(order)
+            inverse[order] = np.arange(len(order))
+            self.tokens = source.tokens[order]    # length-sorted view
+            self.lengths = source.lengths[order]
         self.source = source
         self.order = order          # sorted index -> original index
         self.inverse = inverse      # original index -> sorted index
-        self.tokens = source.tokens[order]    # length-sorted view (numpy)
-        self.lengths = source.lengths[order]
         # Every cached artifact below is derived from the source arrays; an
         # in-place edit after prepare() would silently serve stale sorts and
         # bitmaps.  Seal both the source and the sorted copies — growth goes
@@ -112,7 +114,9 @@ class PreparedCollection:
         """(tokens, lengths) as device (jnp) arrays, cached."""
         if self._device is None:
             import jax.numpy as jnp
-            self._device = (jnp.asarray(self.tokens), jnp.asarray(self.lengths))
+            with tracing.span("prepare.upload"):
+                self._device = (jnp.asarray(self.tokens),
+                                jnp.asarray(self.lengths))
         return self._device
 
     def bitmap_words(self, b: int, method: str, *, mix: bool = False,
@@ -127,8 +131,9 @@ class PreparedCollection:
         key = (int(b), method, bool(mix))
         if key not in self._words:
             tokens, lengths = self.device_arrays()
-            self._words[key] = bm.generate_bitmaps(tokens, lengths, b,
-                                                   method=method, mix=mix)
+            with tracing.span("prepare.bitmap"):
+                self._words[key] = bm.generate_bitmaps(
+                    tokens, lengths, b, method=method, mix=mix)
             self.builds["bitmap"] += 1
         return self._words[key]
 
@@ -151,8 +156,10 @@ class PreparedCollection:
         key = (sim, float(tau))
         if key not in self._windows:
             import jax.numpy as jnp
-            lo, hi = bounds.length_window_int(sim, tau, self.lengths)
-            self._windows[key] = (lo, hi, jnp.asarray(lo), jnp.asarray(hi))
+            with tracing.span("prepare.window"):
+                lo, hi = bounds.length_window_int(sim, tau, self.lengths)
+                self._windows[key] = (lo, hi, jnp.asarray(lo),
+                                      jnp.asarray(hi))
             self.builds["window"] += 1
         return self._windows[key]
 
@@ -174,7 +181,8 @@ class PreparedCollection:
         key = (sim, float(tau), int(ell))
         if key not in self._postings:
             from repro.index.postings import build_postings
-            self._postings[key] = build_postings(self, sim, tau, ell=ell)
+            with tracing.span("prepare.postings"):
+                self._postings[key] = build_postings(self, sim, tau, ell=ell)
             self.builds["postings"] += 1
         return self._postings[key]
 
@@ -423,6 +431,10 @@ class JoinEngine:
     # -- execution -----------------------------------------------------------
 
     def _execute(self, batch):
+        with tracing.span("join"):
+            return self._join(batch)
+
+    def _join(self, batch):
         from repro.core import join as join_mod
 
         if self.store is not None:

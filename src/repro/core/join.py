@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import bitmap as bm
 from repro.core import bounds, expected, verify
 from repro.core.collection import Collection, split_join_args
@@ -139,6 +140,12 @@ class JoinStats:
     overflow_blocks: int = 0      # tiles/chunks escalated to the dense path
     candidates_generated: int = 0  # pre-bitmap candidate pairs (the funnel top)
     postings_expanded: int = 0    # indexed driver: pre-dedup postings entries
+    # Execution cost, not part of the funnel: the summed static capacity
+    # ``cap`` of the device steps dispatched (the indexed driver's entry
+    # stream, the blocked driver's compaction buffer).  It differs between
+    # drivers and modes that share a funnel, so it is left out of equality
+    # and of ``to_dict``.
+    slots: int = dataclasses.field(default=0, compare=False)
 
     @property
     def filter_ratio(self) -> float:
@@ -158,6 +165,7 @@ class JoinStats:
         """Counters + derived ratios as plain JSON-able types (benchmarks
         emit these so filter-ratio/perf trajectories can be diffed)."""
         d = dataclasses.asdict(self)
+        del d["slots"]
         d["filter_ratio"] = self.filter_ratio
         d["precision"] = self.precision
         return d
@@ -196,33 +204,38 @@ def _resident_block_step(
     counts, and whether the candidate count exceeded ``cap`` (the caller then
     escalates this block pair to the dense host-compaction path).
     """
-    win = ((lengths_s[None, :] >= lo_s[:, None])
-           & (lengths_s[None, :] <= hi_s[:, None])
-           & (lengths_r[:, None] > 0) & (lengths_s[None, :] > 0))
-    if diag:
-        win &= (jnp.arange(win.shape[0])[:, None]
-                < jnp.arange(win.shape[1])[None, :])
-    if use_bitmap:
-        cand = kops.candidate_matrix(
-            words_r, words_s, lengths_r, lengths_s, sim=sim, tau=tau,
-            self_join=False, cutoff=cutoff, impl=impl) & win
-    else:
-        cand = win
-    n_win = jnp.sum(win, dtype=jnp.int32)
-    n_cand = jnp.sum(cand, dtype=jnp.int32)
-    ii, jj = jnp.nonzero(cand, size=cap, fill_value=0)
-    slot_ok = jnp.arange(cap) < n_cand
-    o = verify.gathered_overlap(tokens_r, ii, tokens_s, jj, slot_ok)
-    # Integer-exact acceptance (min_overlap_table): bit-identical to the
-    # f64 oracle — f32 thresholds may only ever *prune*, never accept.
-    need = bounds.min_overlap_gather(sim, need_tab, lengths_r[ii],
-                                     lengths_s[jj])
-    ok = slot_ok & (o >= need)
-    n_ok = jnp.sum(ok, dtype=jnp.int32)
-    vi = jnp.nonzero(ok, size=cap, fill_value=0)[0]
-    pairs = jnp.stack([ii[vi].astype(jnp.int32) + r0,
-                       jj[vi].astype(jnp.int32) + s0], axis=1)
-    return pairs, n_win, n_cand, n_ok, n_cand > cap
+    with jax.named_scope(tracing.WINDOW):
+        win = ((lengths_s[None, :] >= lo_s[:, None])
+               & (lengths_s[None, :] <= hi_s[:, None])
+               & (lengths_r[:, None] > 0) & (lengths_s[None, :] > 0))
+        if diag:
+            win &= (jnp.arange(win.shape[0])[:, None]
+                    < jnp.arange(win.shape[1])[None, :])
+        n_win = jnp.sum(win, dtype=jnp.int32)
+    with jax.named_scope(tracing.VERDICT):
+        if use_bitmap:
+            cand = kops.candidate_matrix(
+                words_r, words_s, lengths_r, lengths_s, sim=sim, tau=tau,
+                self_join=False, cutoff=cutoff, impl=impl) & win
+        else:
+            cand = win
+        n_cand = jnp.sum(cand, dtype=jnp.int32)
+    with jax.named_scope(tracing.COMPACT):
+        ii, jj = jnp.nonzero(cand, size=cap, fill_value=0)
+        slot_ok = jnp.arange(cap) < n_cand
+    with jax.named_scope(tracing.VERIFY):
+        o = verify.gathered_overlap(tokens_r, ii, tokens_s, jj, slot_ok)
+        # Integer-exact acceptance (min_overlap_table): bit-identical to the
+        # f64 oracle — f32 thresholds may only ever *prune*, never accept.
+        need = bounds.min_overlap_gather(sim, need_tab, lengths_r[ii],
+                                         lengths_s[jj])
+        ok = slot_ok & (o >= need)
+        n_ok = jnp.sum(ok, dtype=jnp.int32)
+    with jax.named_scope(tracing.COMPACT):
+        vi = jnp.nonzero(ok, size=cap, fill_value=0)[0]
+        pairs = jnp.stack([ii[vi].astype(jnp.int32) + r0,
+                           jj[vi].astype(jnp.int32) + s0], axis=1)
+        return pairs, n_win, n_cand, n_ok, n_cand > cap
 
 
 def _dense_block_verify(
@@ -436,13 +449,14 @@ def blocked_bitmap_join_prepared(
             if capacity is None:
                 # Tile-count prepass: size the capacity from the real counts
                 # (only two int32 grids cross to the host).
-                nwin_t, ncand_t = kops.count_candidates(
-                    words_r[r0:r1], words_s[s0:s1],
-                    lengths_r[r0:r1], lengths_s[s0:s1], win_lo, win_hi,
-                    sim=sim, tau=float(tau), self_join=diag,
-                    cutoff=int(cutoff), impl=impl)
-                n_win = int(np.asarray(nwin_t).sum())
-                n_cand_pre = int(np.asarray(ncand_t).sum())
+                with tracing.span("join.count"):
+                    nwin_t, ncand_t = kops.count_candidates(
+                        words_r[r0:r1], words_s[s0:s1],
+                        lengths_r[r0:r1], lengths_s[s0:s1], win_lo, win_hi,
+                        sim=sim, tau=float(tau), self_join=diag,
+                        cutoff=int(cutoff), impl=impl)
+                    n_win = int(np.asarray(nwin_t).sum())
+                    n_cand_pre = int(np.asarray(ncand_t).sum())
                 stats.total_pairs += n_win
                 if not use_bitmap:
                     n_cand_pre = n_win
@@ -451,46 +465,53 @@ def blocked_bitmap_join_prepared(
                 cap = min(_bucket_capacity(n_cand_pre), (r1 - r0) * (s1 - s0))
             else:
                 cap = int(capacity)
-            pairs_d, n_win_d, n_cand_d, n_ok_d, ovf = _resident_block_step(
-                tokens_r[r0:r1], lengths_r[r0:r1], words_r[r0:r1],
-                tokens_s[s0:s1], lengths_s[s0:s1], words_s[s0:s1],
-                win_lo, win_hi, need_tab, jnp.int32(r0), jnp.int32(s0),
-                sim=sim, tau=float(tau), cap=cap, diag=diag,
-                cutoff=int(cutoff), impl=impl, use_bitmap=use_bitmap)
-            if capacity is not None:
-                stats.total_pairs += int(n_win_d)
-            stats.candidates += int(n_cand_d)
-            if bool(ovf):
+            with tracing.span("join.dispatch"):
+                pairs_d, n_win_d, n_cand_d, n_ok_d, ovf = _resident_block_step(
+                    tokens_r[r0:r1], lengths_r[r0:r1], words_r[r0:r1],
+                    tokens_s[s0:s1], lengths_s[s0:s1], words_s[s0:s1],
+                    win_lo, win_hi, need_tab, jnp.int32(r0), jnp.int32(s0),
+                    sim=sim, tau=float(tau), cap=cap, diag=diag,
+                    cutoff=int(cutoff), impl=impl, use_bitmap=use_bitmap)
+            stats.slots += cap
+            with tracing.span("join.sync"):
+                if capacity is not None:
+                    stats.total_pairs += int(n_win_d)
+                stats.candidates += int(n_cand_d)
+                overflow = bool(ovf)
+                k = int(n_ok_d)
+            if overflow:
                 # Escalation: the fixed-capacity list truncated this tile —
                 # re-run it densely (host compaction) for exactness.  The
                 # counters above are exact (counted before truncation).
                 stats.overflow_blocks += 1
-                _, _, vpairs = _dense_block_verify(
-                    tokens_r, lengths_r, words_r, tokens_s, lengths_s, words_s,
-                    np_len_r, np_len_s, r0, r1, s0, s1,
-                    sim=sim, tau=tau, cutoff=cutoff, impl=impl, diag=diag,
-                    self_join=self_join, use_bitmap=use_bitmap)
+                with tracing.span("join.fallback"):
+                    _, _, vpairs = _dense_block_verify(
+                        tokens_r, lengths_r, words_r, tokens_s, lengths_s,
+                        words_s, np_len_r, np_len_s, r0, r1, s0, s1,
+                        sim=sim, tau=tau, cutoff=cutoff, impl=impl,
+                        diag=diag, self_join=self_join, use_bitmap=use_bitmap)
                 stats.verified_true += len(vpairs)
                 if len(vpairs):
                     pairs_out.append(np.stack(
                         [order_r[vpairs[:, 0]], order_s[vpairs[:, 1]]], axis=1))
                 continue
-            k = int(n_ok_d)
             stats.verified_true += k
             if k:
-                vp = np.asarray(pairs_d)[:k].astype(np.int64)
+                with tracing.span("join.fetch"):
+                    vp = np.asarray(pairs_d)[:k].astype(np.int64)
                 pairs_out.append(np.stack(
                     [order_r[vp[:, 0]], order_s[vp[:, 1]]], axis=1))
 
-    if pairs_out:
-        pairs = np.concatenate(pairs_out, axis=0)
-        if self_join:
-            lo = np.minimum(pairs[:, 0], pairs[:, 1])
-            hi_ = np.maximum(pairs[:, 0], pairs[:, 1])
-            pairs = np.stack([lo, hi_], axis=1)
-        pairs = pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]
-    else:
-        pairs = np.zeros((0, 2), dtype=np.int64)
+    with tracing.span("join.finish"):
+        if pairs_out:
+            pairs = np.concatenate(pairs_out, axis=0)
+            if self_join:
+                lo = np.minimum(pairs[:, 0], pairs[:, 1])
+                hi_ = np.maximum(pairs[:, 0], pairs[:, 1])
+                pairs = np.stack([lo, hi_], axis=1)
+            pairs = pairs[np.lexsort((pairs[:, 1], pairs[:, 0]))]
+        else:
+            pairs = np.zeros((0, 2), dtype=np.int64)
     # Grid driver: the bitmap verdict is consumed on every window-surviving
     # cell, so the funnel top equals the windowed grid (set identically on
     # both compaction paths — the stats stay bit-for-bit comparable).

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import bitmap as bm
 from repro.core import bounds, expected, verify
 from repro.core.collection import Collection, split_join_args
@@ -124,35 +125,37 @@ def expand_and_filter(
     slots hold ``_INT32_MAX``) ready for :func:`dedup_pairs`, plus the exact
     expansion count of this view.
     """
-    c = probe_tokens.shape[0]
+    with jax.named_scope(tracing.EXPAND):
+        c = probe_tokens.shape[0]
 
-    # -- expand: window-narrowed CSR lookups per (probe, prefix position) --
-    rng_start, cnt2d = _windowed_ranges(
-        vocab, vocab_tid, post_key, probe_tokens, probe_prefix, lo_r, hi_r,
-        lp, scale)
-    rng_flat = rng_start.reshape(-1)
-    cnt = cnt2d.reshape(-1)
-    seg_end = jnp.cumsum(cnt)
-    n_expanded = seg_end[-1]
+        # -- expand: window-narrowed CSR lookups per (probe, prefix pos) --
+        rng_start, cnt2d = _windowed_ranges(
+            vocab, vocab_tid, post_key, probe_tokens, probe_prefix, lo_r,
+            hi_r, lp, scale)
+        rng_flat = rng_start.reshape(-1)
+        cnt = cnt2d.reshape(-1)
+        seg_end = jnp.cumsum(cnt)
+        n_expanded = seg_end[-1]
 
-    g = jnp.arange(cap, dtype=jnp.int32)
-    k = jnp.clip(jnp.searchsorted(seg_end, g, side="right"), 0, c * lp - 1)
-    in_range = g < n_expanded
-    within = g - (seg_end[k] - cnt[k])
-    pidx = jnp.clip(rng_flat[k] + within, 0, post_set.shape[0] - 1)
-    r_idx = post_set[pidx]
-    s_loc = (k // lp).astype(jnp.int32)
+        g = jnp.arange(cap, dtype=jnp.int32)
+        k = jnp.clip(jnp.searchsorted(seg_end, g, side="right"), 0,
+                     c * lp - 1)
+        in_range = g < n_expanded
+        within = g - (seg_end[k] - cnt[k])
+        pidx = jnp.clip(rng_flat[k] + within, 0, post_set.shape[0] - 1)
+        r_idx = post_set[pidx]
+        s_loc = (k // lp).astype(jnp.int32)
 
-    # -- filter: length window + positional bound + triangle, on device ----
-    keep = kops.entry_filter(
-        post_len[pidx], post_pos[pidx],
-        probe_lengths[s_loc], (k % lp).astype(jnp.int32),
-        lo_r[s_loc], hi_r[s_loc],
-        r_idx, s0 + s_loc, in_range,
-        sim=sim, tau=tau, self_join=self_join, impl=impl)
+        # -- filter: length window + positional bound + triangle --------
+        keep = kops.entry_filter(
+            post_len[pidx], post_pos[pidx],
+            probe_lengths[s_loc], (k % lp).astype(jnp.int32),
+            lo_r[s_loc], hi_r[s_loc],
+            r_idx, s0 + s_loc, in_range,
+            sim=sim, tau=tau, self_join=self_join, impl=impl)
 
-    rr = jnp.where(keep, r_idx, _INT32_MAX)
-    ss = jnp.where(keep, s_loc, _INT32_MAX)
+        rr = jnp.where(keep, r_idx, _INT32_MAX)
+        ss = jnp.where(keep, s_loc, _INT32_MAX)
     return rr, ss, n_expanded
 
 
@@ -167,17 +170,20 @@ def dedup_pairs(rr, ss, cap: int):
     with itself, which is exactly how the sharded driver re-deduplicates
     the allgathered per-shard buffers.
     """
-    # (two int32 sort keys rather than one fused int64 key: x64 stays off)
-    order = jnp.lexsort((rr, ss))  # s major, r minor; pruned slots sort last
-    sr = rr[order]
-    s2 = ss[order]
-    uniq = (s2 != _INT32_MAX) & jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), (s2[1:] != s2[:-1]) | (sr[1:] != sr[:-1])])
-    n_generated = jnp.sum(uniq, dtype=jnp.int32)
-    ui = jnp.nonzero(uniq, size=cap, fill_value=0)[0]
-    slot_ok = jnp.arange(cap) < n_generated
-    cand_r = jnp.where(slot_ok, sr[ui], _INT32_MAX)
-    cand_s = jnp.where(slot_ok, s2[ui], _INT32_MAX)
+    with jax.named_scope(tracing.DEDUP):
+        # (two int32 sort keys rather than one fused int64 key: x64 stays off)
+        # s major, r minor; pruned slots sort last
+        order = jnp.lexsort((rr, ss))
+        sr = rr[order]
+        s2 = ss[order]
+        uniq = (s2 != _INT32_MAX) & jnp.concatenate(
+            [jnp.ones((1,), dtype=bool),
+             (s2[1:] != s2[:-1]) | (sr[1:] != sr[:-1])])
+        n_generated = jnp.sum(uniq, dtype=jnp.int32)
+        ui = jnp.nonzero(uniq, size=cap, fill_value=0)[0]
+        slot_ok = jnp.arange(cap) < n_generated
+        cand_r = jnp.where(slot_ok, sr[ui], _INT32_MAX)
+        cand_s = jnp.where(slot_ok, s2[ui], _INT32_MAX)
     return cand_r, cand_s, n_generated
 
 
@@ -198,24 +204,27 @@ def verdict_and_verify(
     per-request funnel counters from a coalesced batch.
     """
     cap = cand_r.shape[0]
-    safe_r = jnp.where(slot_ok, cand_r, 0)
-    safe_s = jnp.where(slot_ok, cand_s, 0)
-    bm_pass = kops.pair_verdict(
-        words_r[safe_r], probe_words[safe_s],
-        lengths_r[safe_r], probe_lengths[safe_s],
-        sim=sim, tau=tau, cutoff=cutoff, impl=impl)
-    cand_mask = slot_ok & bm_pass
-    n_bitmap = jnp.sum(cand_mask, dtype=jnp.int32)
-    o = verify.gathered_overlap(tokens_r, safe_r, probe_tokens, safe_s,
-                                cand_mask)
-    # Integer-exact acceptance (min_overlap_table) — identical to the
-    # f64 oracle; f32 thresholds are prune-only in this driver too.
-    need = bounds.min_overlap_gather(
-        sim, need_tab, lengths_r[safe_r], probe_lengths[safe_s])
-    ok = cand_mask & (o >= need)
-    n_verified = jnp.sum(ok, dtype=jnp.int32)
-    vi = jnp.nonzero(ok, size=cap, fill_value=0)[0]
-    pairs = jnp.stack([safe_r[vi], safe_s[vi] + s0], axis=1)
+    with jax.named_scope(tracing.VERDICT):
+        safe_r = jnp.where(slot_ok, cand_r, 0)
+        safe_s = jnp.where(slot_ok, cand_s, 0)
+        bm_pass = kops.pair_verdict(
+            words_r[safe_r], probe_words[safe_s],
+            lengths_r[safe_r], probe_lengths[safe_s],
+            sim=sim, tau=tau, cutoff=cutoff, impl=impl)
+        cand_mask = slot_ok & bm_pass
+        n_bitmap = jnp.sum(cand_mask, dtype=jnp.int32)
+    with jax.named_scope(tracing.VERIFY):
+        o = verify.gathered_overlap(tokens_r, safe_r, probe_tokens, safe_s,
+                                    cand_mask)
+        # Integer-exact acceptance (min_overlap_table) — identical to the
+        # f64 oracle; f32 thresholds are prune-only in this driver too.
+        need = bounds.min_overlap_gather(
+            sim, need_tab, lengths_r[safe_r], probe_lengths[safe_s])
+        ok = cand_mask & (o >= need)
+        n_verified = jnp.sum(ok, dtype=jnp.int32)
+    with jax.named_scope(tracing.COMPACT):
+        vi = jnp.nonzero(ok, size=cap, fill_value=0)[0]
+        pairs = jnp.stack([safe_r[vi], safe_s[vi] + s0], axis=1)
     if return_masks:
         return pairs, n_bitmap, n_verified, cand_mask, ok
     return pairs, n_bitmap, n_verified
@@ -256,7 +265,8 @@ def _indexed_chunk_step(
         sim=sim, tau=tau, cap=cap, lp=lp, scale=scale, self_join=self_join,
         impl=impl)
     cand_r, cand_s, n_generated = dedup_pairs(rr, ss, cap)
-    slot_ok = jnp.arange(cap) < n_generated
+    with jax.named_scope(tracing.DEDUP):
+        slot_ok = jnp.arange(cap) < n_generated
     pairs, n_bitmap, n_verified = verdict_and_verify(
         tokens_r, lengths_r, words_r, probe_tokens, probe_lengths,
         probe_words, cand_r, cand_s, slot_ok, need_tab, s0,
@@ -332,17 +342,20 @@ def _fallback_mask(words_r, lengths_r, words_c, lengths_c, lo_c, hi_c, s0,
                    *, sim, tau, cutoff, impl, self_join):
     """Grid verdict ∩ length window over R × chunk, on device ->
     ``(mask bool[NR, C], window cells per R row, R rows with a candidate)``."""
-    cand = kops.candidate_matrix(
-        words_r, words_c, lengths_r, lengths_c, sim=sim, tau=tau,
-        self_join=False, cutoff=cutoff, impl=impl)
-    lr = lengths_r[:, None]
-    win = ((lr >= lo_c[None, :]) & (lr <= hi_c[None, :])
-           & (lr > 0) & (lengths_c[None, :] > 0))
-    if self_join:
-        win &= (jnp.arange(lr.shape[0])[:, None]
-                < (s0 + jnp.arange(lengths_c.shape[0]))[None, :])
-    mask = cand & win
-    return mask, jnp.sum(win, axis=1, dtype=jnp.int32), jnp.any(mask, axis=1)
+    with jax.named_scope(tracing.VERDICT):
+        cand = kops.candidate_matrix(
+            words_r, words_c, lengths_r, lengths_c, sim=sim, tau=tau,
+            self_join=False, cutoff=cutoff, impl=impl)
+    with jax.named_scope(tracing.WINDOW):
+        lr = lengths_r[:, None]
+        win = ((lr >= lo_c[None, :]) & (lr <= hi_c[None, :])
+               & (lr > 0) & (lengths_c[None, :] > 0))
+        if self_join:
+            win &= (jnp.arange(lr.shape[0])[:, None]
+                    < (s0 + jnp.arange(lengths_c.shape[0]))[None, :])
+        mask = cand & win
+        return (mask, jnp.sum(win, axis=1, dtype=jnp.int32),
+                jnp.any(mask, axis=1))
 
 
 def _dense_chunk_fallback(tokens_r, lengths_r, words_r, tokens_c, lengths_c,
@@ -470,7 +483,8 @@ def indexed_join_prepared(
     stats = JoinStats()
 
     def _finish(pairs_list):
-        pairs = finish_pairs(prep_r, prep_s, self_join, pairs_list)
+        with tracing.span("join.finish"):
+            pairs = finish_pairs(prep_r, prep_s, self_join, pairs_list)
         return (pairs, stats) if return_stats else pairs
 
     post = prep_r.postings(sim, tau, ell)
@@ -498,9 +512,10 @@ def indexed_join_prepared(
     for c0 in range(0, ns, cb):
         c1 = min(c0 + cb, ns)
         stats.blocks_total += 1
-        n_exp = _expansion_count_host(
-            post, prep_s.tokens[c0:c1], ps_np[c0:c1],
-            lo_np[c0:c1], hi_np[c0:c1], lp, scale)
+        with tracing.span("join.count"):
+            n_exp = _expansion_count_host(
+                post, prep_s.tokens[c0:c1], ps_np[c0:c1],
+                lo_np[c0:c1], hi_np[c0:c1], lp, scale)
         stats.postings_expanded += n_exp
         if n_exp == 0:
             stats.blocks_skipped += 1
@@ -515,12 +530,13 @@ def indexed_join_prepared(
             # hot-token chunk) — escalate the whole chunk to the dense
             # grid fallback.
             stats.overflow_blocks += 1
-            n_win, n_bm, vpairs = _dense_chunk_fallback(
-                tokens_r, lengths_r, words_r,
-                tokens_s[c0:c1], lengths_s[c0:c1], words_s[c0:c1],
-                np.asarray(lo_d[c0:c1]), np.asarray(hi_d[c0:c1]), c0,
-                sim=sim, tau=tau, cutoff=cutoff, impl=impl,
-                self_join=self_join)
+            with tracing.span("join.fallback"):
+                n_win, n_bm, vpairs = _dense_chunk_fallback(
+                    tokens_r, lengths_r, words_r,
+                    tokens_s[c0:c1], lengths_s[c0:c1], words_s[c0:c1],
+                    np.asarray(lo_d[c0:c1]), np.asarray(hi_d[c0:c1]), c0,
+                    sim=sim, tau=tau, cutoff=cutoff, impl=impl,
+                    self_join=self_join)
             stats.total_pairs += n_win
             stats.candidates_generated += n_win
             stats.candidates += n_bm
@@ -528,23 +544,28 @@ def indexed_join_prepared(
             if len(vpairs):
                 pairs_out.append(vpairs)
             continue
-        pairs_d, _, n_gen, n_bm, n_ok = _indexed_chunk_step(
-            tokens_r, lengths_r, words_r, *csr,
-            _pad_chunk(tokens_s[c0:c1], cb, PAD_TOKEN),
-            _pad_chunk(lengths_s[c0:c1], cb, 0),
-            _pad_chunk(words_s[c0:c1], cb, 0),
-            _pad_chunk(ps_d[c0:c1], cb, 0),
-            _pad_chunk(lo_d[c0:c1], cb, 0), _pad_chunk(hi_d[c0:c1], cb, 0),
-            need_tab, jnp.int32(c0),
-            sim=sim, tau=float(tau), cap=cap, lp=lp, scale=scale,
-            self_join=self_join, cutoff=int(cutoff), impl=impl)
-        stats.total_pairs += int(n_gen)
-        stats.candidates_generated += int(n_gen)
-        stats.candidates += int(n_bm)
-        k = int(n_ok)
+        with tracing.span("join.dispatch"):
+            pairs_d, _, n_gen, n_bm, n_ok = _indexed_chunk_step(
+                tokens_r, lengths_r, words_r, *csr,
+                _pad_chunk(tokens_s[c0:c1], cb, PAD_TOKEN),
+                _pad_chunk(lengths_s[c0:c1], cb, 0),
+                _pad_chunk(words_s[c0:c1], cb, 0),
+                _pad_chunk(ps_d[c0:c1], cb, 0),
+                _pad_chunk(lo_d[c0:c1], cb, 0),
+                _pad_chunk(hi_d[c0:c1], cb, 0),
+                need_tab, jnp.int32(c0),
+                sim=sim, tau=float(tau), cap=cap, lp=lp, scale=scale,
+                self_join=self_join, cutoff=int(cutoff), impl=impl)
+        stats.slots += cap
+        with tracing.span("join.sync"):
+            n_gen, n_bm, k = int(n_gen), int(n_bm), int(n_ok)
+        stats.total_pairs += n_gen
+        stats.candidates_generated += n_gen
+        stats.candidates += n_bm
         stats.verified_true += k
         if k:
-            pairs_out.append(np.asarray(pairs_d)[:k].astype(np.int64))
+            with tracing.span("join.fetch"):
+                pairs_out.append(np.asarray(pairs_d)[:k].astype(np.int64))
 
     return _finish(pairs_out)
 

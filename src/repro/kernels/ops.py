@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import bounds
 from repro.core.bitmap import popcount_rows, unpack_bits
 from repro.kernels import bitplane, bitmap_filter, compaction, postings, ref
@@ -189,31 +190,36 @@ def count_candidates(
     the dense mask.  The resident join sizes its compaction capacity from
     these counts.  Non-Pallas impls (``ref``/``ref_mxu``/``mxu``) share the
     pure-jnp oracle; the dense intermediate then lives only on device inside
-    this jit.
+    this jit.  One fused pass counts the verdict and the window together, so
+    its device time is the ``verdict`` stage's.
     """
-    nr, w = words_r.shape
-    ns, _ = words_s.shape
-    b = 32 * w
-    impl = resolve_impl(impl, b)
-    if interpret is None:
-        interpret = not _on_tpu()
-    if impl in ("ref", "ref_mxu", "mxu"):
-        return ref.count_candidates_ref(
-            words_r, words_s, len_r, len_s, lo_s, hi_s, sim=sim, tau=tau,
-            self_join=self_join, cutoff=cutoff, window=window,
-            tile_r=tile, tile_s=tile)
-    if impl != "swar":
-        raise ValueError(f"unknown impl {impl!r}")
-    pr = _pad_rows(words_r, tile)
-    ps = _pad_rows(words_s, tile)
-    plr = _pad_rows(len_r.astype(jnp.int32), tile)
-    pls = _pad_rows(len_s.astype(jnp.int32), tile)
-    plo = _pad_rows(lo_s.astype(jnp.int32), tile)
-    phi = _pad_rows(hi_s.astype(jnp.int32), tile)
-    return compaction.count_candidates_pallas(
-        pr, ps, plr, pls, plo, phi, sim=sim, tau=tau, self_join=self_join,
-        cutoff=cutoff, window=window, tile_r=tile, tile_s=tile,
-        interpret=interpret)
+    # A Pallas call takes the innermost scope's name as its HLO name: the
+    # inner scope keeps it ``%count_candidates``, as the benchmark's layer
+    # table of the verdict kernels expects.
+    with jax.named_scope(tracing.VERDICT), jax.named_scope("count_candidates"):
+        nr, w = words_r.shape
+        ns, _ = words_s.shape
+        b = 32 * w
+        impl = resolve_impl(impl, b)
+        if interpret is None:
+            interpret = not _on_tpu()
+        if impl in ("ref", "ref_mxu", "mxu"):
+            return ref.count_candidates_ref(
+                words_r, words_s, len_r, len_s, lo_s, hi_s, sim=sim, tau=tau,
+                self_join=self_join, cutoff=cutoff, window=window,
+                tile_r=tile, tile_s=tile)
+        if impl != "swar":
+            raise ValueError(f"unknown impl {impl!r}")
+        pr = _pad_rows(words_r, tile)
+        ps = _pad_rows(words_s, tile)
+        plr = _pad_rows(len_r.astype(jnp.int32), tile)
+        pls = _pad_rows(len_s.astype(jnp.int32), tile)
+        plo = _pad_rows(lo_s.astype(jnp.int32), tile)
+        phi = _pad_rows(hi_s.astype(jnp.int32), tile)
+        return compaction.count_candidates_pallas(
+            pr, ps, plr, pls, plo, phi, sim=sim, tau=tau, self_join=self_join,
+            cutoff=cutoff, window=window, tile_r=tile, tile_s=tile,
+            interpret=interpret)
 
 
 def _resolve_pairwise_impl(impl: str, b: int) -> str:

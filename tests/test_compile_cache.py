@@ -26,3 +26,18 @@ def test_fixed_in_repo_dir_without_env(monkeypatch, cache_dir_config):
     assert path == str(compile_cache.REPO_ROOT / ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == path
     assert compile_cache.enable() == path  # the same path every call
+
+
+def test_the_key_holds_operation_metadata(monkeypatch, cache_dir_config):
+    """Stage names live in metadata: a cached executable of a build that
+    named other stages must not be served."""
+    before = jax.config.jax_compilation_cache_include_metadata_in_key
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    try:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        compile_cache.enable()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          before)
