@@ -56,6 +56,7 @@ from repro.core import bounds, expected, verify
 from repro.core.collection import Collection, split_join_args
 from repro.core.constants import BITMAP_COMBINED, JACCARD, PAD_TOKEN
 from repro.core.engine import PreparedCollection, as_prepared
+from repro.kernels import compaction as kcompact
 from repro.kernels import ops as kops
 
 
@@ -146,6 +147,9 @@ class JoinStats:
     # drivers and modes that share a funnel, so it is left out of equality
     # and of ``to_dict``.
     slots: int = dataclasses.field(default=0, compare=False)
+    # The part of ``slots`` whose tile compaction took the lookup form
+    # (``kernels.compaction.compact_indices``); execution cost, like slots.
+    gather_slots: int = dataclasses.field(default=0, compare=False)
 
     @property
     def filter_ratio(self) -> float:
@@ -165,7 +169,7 @@ class JoinStats:
         """Counters + derived ratios as plain JSON-able types (benchmarks
         emit these so filter-ratio/perf trajectories can be diffed)."""
         d = dataclasses.asdict(self)
-        del d["slots"]
+        del d["slots"], d["gather_slots"]
         d["filter_ratio"] = self.filter_ratio
         d["precision"] = self.precision
         return d
@@ -194,10 +198,11 @@ def _resident_block_step(
     candidate list, TPU-shaped).
 
     Bitmap verdict -> integer length-window mask -> fixed-capacity compaction
-    (``jnp.nonzero(size=cap)``) -> exact searchsorted verification -> second
-    compaction down to verified pairs, all inside one jit.  Only the
-    ``(cap, 2)`` compacted pair buffer and five scalars are ever transferred
-    to the host; the dense ``(TR, TS)`` verdict tile never leaves the device.
+    (``compact_indices``, which is ``jnp.nonzero(size=cap)`` bit for bit) ->
+    exact searchsorted verification -> second compaction down to verified
+    pairs, all inside one jit.  Only the ``(cap, 2)`` compacted pair buffer
+    and five scalars are ever transferred to the host; the dense ``(TR, TS)``
+    verdict tile never leaves the device.
 
     Returns ``(pairs, n_win, n_cand, n_ok, overflow)``: global sorted-index
     pairs (slots ``>= n_ok`` are garbage), window-pair / candidate / verified
@@ -221,7 +226,7 @@ def _resident_block_step(
             cand = win
         n_cand = jnp.sum(cand, dtype=jnp.int32)
     with jax.named_scope(tracing.COMPACT):
-        ii, jj = jnp.nonzero(cand, size=cap, fill_value=0)
+        ii, jj = kcompact.compact_indices(cand, cap)
         slot_ok = jnp.arange(cap) < n_cand
     with jax.named_scope(tracing.VERIFY):
         o = verify.gathered_overlap(tokens_r, ii, tokens_s, jj, slot_ok)
@@ -232,7 +237,7 @@ def _resident_block_step(
         ok = slot_ok & (o >= need)
         n_ok = jnp.sum(ok, dtype=jnp.int32)
     with jax.named_scope(tracing.COMPACT):
-        vi = jnp.nonzero(ok, size=cap, fill_value=0)[0]
+        (vi,) = kcompact.compact_indices(ok, cap)
         pairs = jnp.stack([ii[vi].astype(jnp.int32) + r0,
                            jj[vi].astype(jnp.int32) + s0], axis=1)
         return pairs, n_win, n_cand, n_ok, n_cand > cap
@@ -473,6 +478,8 @@ def blocked_bitmap_join_prepared(
                     sim=sim, tau=float(tau), cap=cap, diag=diag,
                     cutoff=int(cutoff), impl=impl, use_bitmap=use_bitmap)
             stats.slots += cap
+            if kcompact.lookup_applies((r1 - r0) * (s1 - s0), cap):
+                stats.gather_slots += cap
             with tracing.span("join.sync"):
                 if capacity is not None:
                     stats.total_pairs += int(n_win_d)

@@ -16,9 +16,15 @@ of window-surviving pairs and the number of bitmap candidates.  O(NR*NS)
 compute like the filter itself, but only ``O(grid)`` bytes cross to the
 host — the dense bool verdict tile the host-compaction path ships never
 leaves the device.
+
+:func:`compact_indices` is the step's compaction itself: what
+``jnp.nonzero(mask, size=cap, fill_value=0)`` returns, bit for bit, in the
+form that does less work for the mask's shape and ``cap``.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -101,3 +107,50 @@ def count_candidates_pallas(
         interpret=interpret,
     )(words_r, words_s.T, lr, ls, lo_s.reshape(-1, 1), hi_s.reshape(-1, 1))
     return nwin[::bh, ::bw], ncand[::bh, ::bw]
+
+
+# Binary-search loop of the lookup form (``jnp.searchsorted``'s ``method``).
+_SEARCH_METHOD = "scan"
+
+
+def lookup_applies(size: int, cap: int) -> bool:
+    """Whether :func:`compact_indices` takes the lookup form for a mask of
+    ``size`` cells and ``cap`` output slots: its ``cap`` binary searches of
+    ``ceil(log2(size))`` probes each do no more work than the scatter
+    form's one update per mask cell."""
+    return size > 0 and cap * math.ceil(math.log2(size)) <= size
+
+
+def _lookup_form(mask, cap: int, method: str = _SEARCH_METHOD):
+    # Slot k holds the first flat position whose running count reaches
+    # k + 1; slots at or past the count hold 0, as nonzero's fill does.
+    cs = jnp.cumsum(mask.ravel(), dtype=jnp.int32)
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    flat = jnp.searchsorted(cs, slot + 1, side="left", method=method)
+    flat = jnp.where(slot < cs[-1], flat, 0).astype(jnp.int32)
+    out = []
+    for dim in reversed(mask.shape[1:]):
+        out.append(flat % dim)
+        flat = flat // dim
+    return (flat, *reversed(out))
+
+
+def compact_indices(mask: jnp.ndarray, cap: int, *, _form: str | None = None):
+    """``jnp.nonzero(mask, size=cap, fill_value=0)`` of a bool ``mask``,
+    bit for bit: a tuple of int32[cap], one per axis, holding the first
+    ``cap`` true cells in row-major order and 0 past the true count.
+
+    Two forms, chosen from the static shapes alone
+    (:func:`lookup_applies`).  The scatter form is ``jnp.nonzero``, whose
+    ``bincount`` adds one update per mask cell; the lookup form takes the
+    mask's running count and binary-searches it once per slot, so its work
+    grows with ``cap`` rather than with the mask's area.  ``_form``
+    ("lookup" or "scatter") forces one, for tests.
+    """
+    if _form is None:
+        _form = "lookup" if lookup_applies(mask.size, cap) else "scatter"
+    if _form == "lookup":
+        return _lookup_form(mask, cap)
+    if _form == "scatter":
+        return jnp.nonzero(mask, size=cap, fill_value=0)
+    raise ValueError(f"_form must be 'lookup' or 'scatter', got {_form!r}")

@@ -3,9 +3,10 @@
 Every kernel that ``impl="auto"`` resolves to on a TPU is compiled here for
 a described (not attached) ``v5e:2x2`` topology at real widths — 4096-row
 tiles, 16384 candidates — at b=128 and b=512, plus the indexed driver's
-whole fused chunk step.  A compile is not a chip run: it proves only that
-Mosaic and XLA accept the program.  ``test_auto_routes_only_to_compiled``
-pins ``auto`` to exactly the impls compiled here.
+whole fused chunk step and the blocked driver's device step.  A compile is
+not a chip run: it proves only that Mosaic and XLA accept the program.
+``test_auto_routes_only_to_compiled`` pins ``auto`` to exactly the impls
+compiled here.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU compiler library at a time, and every pytest
@@ -142,6 +143,28 @@ def test_indexed_chunk_step_compiles(tpu_compile, b):
     statics["cap"] = N_CAND
     shapes = [spec(np.shape(a), a.dtype) for a in args]
     text = cands._indexed_chunk_step.lower(*shapes, **statics).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_resident_block_step_compiles(tpu_compile):
+    """The blocked driver's whole device step at a 4096x4096 tile, b=512 and
+    869-token rows (DBLP's widest set), with a cap whose tile compaction
+    takes the lookup form."""
+    from repro.core import join
+    from repro.kernels import compaction
+
+    spec, _ = tpu_compile
+    width, cap = 869, 4096
+    assert compaction.lookup_applies(N_ROWS * N_ROWS, cap)
+    tokens = spec((N_ROWS, width), jnp.int32)
+    ints = spec((N_ROWS,), jnp.int32)
+    words = spec((N_ROWS, 512 // 32), jnp.uint32)
+    need = spec((2 * width + 1,), jnp.int32)
+    row0 = spec((), jnp.int32)
+    text = join._resident_block_step.lower(
+        tokens, ints, words, tokens, ints, words, ints, ints, need, row0,
+        row0, sim="jaccard", tau=0.5, cap=cap, diag=False, cutoff=1 << 30,
+        impl="auto").compile().as_text()
     assert "tpu_custom_call" in text
 
 

@@ -5,12 +5,15 @@ oracle across word widths W ∈ {1, 4, 128}, non-multiple-of-tile NR/NS,
 all-pass and all-prune tiles, and empty (length-0 padding) rows.
 """
 
+import itertools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from repro.core import bitmap as bm, bounds
 from repro.core.constants import PAD_TOKEN
+from repro.kernels import compaction
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
@@ -151,3 +154,37 @@ def test_count_kernel_matches_dense_candidate_matrix():
            & (lens_r[:, None] > 0) & (lens_s[None, :] > 0))
     assert int(np.asarray(cand_c).sum()) == int((dense & win).sum())
     assert int(np.asarray(win_c).sum()) == int(win.sum())
+
+
+# compact_indices: both forms against jnp.nonzero(size=cap, fill_value=0),
+# over 1-D and 2-D masks of odd shapes, densities 0 / 0.02% / 30% / 100%,
+# and cap below, equal to and above the true count (below: the first cap
+# true cells in row-major order).
+@pytest.mark.parametrize("form,shape,density,cap_vs_count", list(
+    itertools.product(("lookup", "scatter"), ((37,), (33, 65)),
+                      (0.0, 0.0002, 0.3, 1.0), ("below", "equal", "above"))))
+def test_compact_indices_is_nonzero(form, shape, density, cap_vs_count):
+    rng = np.random.default_rng(7)
+    size = int(np.prod(shape))
+    count = 0 if density == 0 else max(1, round(density * size))
+    flat = np.zeros(size, dtype=bool)
+    flat[rng.choice(size, size=count, replace=False)] = True
+    mask = jnp.asarray(flat.reshape(shape))
+    cap = {"below": max(count // 2, 1), "equal": max(count, 1),
+           "above": count + 7}[cap_vs_count]
+    want = jnp.nonzero(mask, size=cap, fill_value=0)
+    got = compaction.compact_indices(mask, cap, _form=form)
+    assert len(got) == len(shape)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == (cap,)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("size,cap,form", [
+    (4096 * 4096, 4096, "lookup"),      # a sparse blocked-join tile
+    (4096 * 4096, 1 << 19, "lookup"),
+    (16 * 16, 256, "scatter"),          # the CPU tests' block=16 tiles
+    (4096, 4096, "scatter"),            # the cap-sized second compaction
+])
+def test_compaction_form_follows_the_shapes(size, cap, form):
+    assert compaction.lookup_applies(size, cap) == (form == "lookup")

@@ -85,6 +85,27 @@ def test_device_resident_join_matches_oracle(seed, simtau, kind):
     _check_invariants(dstats)
 
 
+@pytest.mark.parametrize("kind", ["dup_heavy", "skewed"])
+def test_lookup_compaction_matches_oracle(kind):
+    """Blocks of 512 give sparse tiles, so the resident step compacts them
+    by lookup (``kernels.compaction.compact_indices``; the skewed corpus
+    also has tiles dense enough for the scatter form): pairs equal the
+    oracle's and the host path's, counters the host path's."""
+    col = _collection(kind, seed=11, n=2048)
+    oracle = join.naive_join(col, "jaccard", 0.8)
+    host, hstats = join.blocked_bitmap_join(
+        col, "jaccard", 0.8, b=32, block=512, return_stats=True)
+    dev, dstats = join.blocked_bitmap_join(
+        col, "jaccard", 0.8, b=32, block=512, compaction="device",
+        return_stats=True)
+    assert np.array_equal(oracle, host), (kind, len(oracle), len(host))
+    assert np.array_equal(oracle, dev), (kind, len(oracle), len(dev))
+    assert hstats == dstats, (hstats, dstats)
+    assert 0 < dstats.gather_slots <= dstats.slots, dstats
+    assert hstats.gather_slots == 0
+    _check_invariants(dstats)
+
+
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 10_000), simtau=st.sampled_from(SIM_TAUS),
        cap=st.sampled_from((1, 2, 4, 8)))
